@@ -51,6 +51,9 @@ _OUT_JSON = _REPO_ROOT / "benchmarks" / "out" / "BENCH_fig9.json"
 
 def _run_timed_raw(code: str, devices: int) -> str:
     env = dict(os.environ)
+    # the children measure forced host devices by design: pin them to the
+    # CPU so none reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.pathsep.join(
         [str(_SRC)] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

@@ -280,25 +280,31 @@ class CholeskyConfig:
 
         Single-device resolves to ``'jax'``.  Multi-device resolves to
         ``'jax'`` whenever the process sees at least ``ndev`` JAX devices
-        (the per-device executor replays the streams on real devices) and
-        falls back to the ``'numpy'`` host replay otherwise.  An explicit
-        ``backend='jax'`` with too few devices raises at ``compile()``
-        instead of silently degrading.
+        (the per-device executor replays the streams on real devices).
+        Only on the CPU backend does it fall back to the ``'numpy'`` host
+        replay (too few devices, or a multi-device spill schedule, which
+        only the replay runs); on an accelerator those cases raise, so a
+        run never leaves the device unnoticed.  An explicit
+        ``backend='jax'`` with too few devices raises at ``compile()``.
         """
         if self.backend != "auto":
             return self.backend
-        if self.ndev > 1 and self.host_slots > 0:
-            # the multi-device spill replay is numpy-only (the jax
-            # executor keeps full row slabs device-resident)
-            return "numpy"
         if self.ndev == 1:
             return "jax"
-        try:
-            import jax
-            n_visible = len(jax.devices())
-        except Exception:
+        import jax
+        devices = jax.devices()
+        if self.host_slots == 0 and len(devices) >= self.ndev:
+            return "jax"
+        if devices[0].platform == "cpu":
             return "numpy"
-        return "jax" if n_visible >= self.ndev else "numpy"
+        why = ("host_slots > 0 with ndev > 1 runs only on the NumPy replay"
+               if self.host_slots > 0 else
+               f"ndev={self.ndev} needs {self.ndev} devices, found "
+               f"{len(devices)}")
+        raise RuntimeError(
+            f"backend='auto' will not leave the {devices[0].platform} for "
+            f"the NumPy replay: {why}; pass backend='numpy' to run it on "
+            f"the host")
 
     def specialize(self, a: np.ndarray) -> "CholeskyConfig":
         """Freeze the matrix-dependent precision plan into the config.
@@ -437,12 +443,15 @@ class OOCSolver:
         pools one solver per session instead of sharing one solver
         across tenants.
         """
-        a = np.asarray(a, dtype=np.float64)
+        a = np.asarray(a)
         if a.shape != (self.n, self.n):
             raise ValueError(
                 f"matrix shape {a.shape} does not match the plan's "
                 f"n={self.n}; build a new plan for a different size")
-        tiles = to_tiles(a, self._plan.config.tb)
+        # tile first, widen second: an f32 matrix never gets a dense f64
+        # copy next to its f64 tiles
+        tiles = to_tiles(a, self._plan.config.tb).astype(np.float64,
+                                                          copy=False)
         cfg = self._plan.config
         if trace is None:
             trace = self._default_trace
@@ -511,8 +520,11 @@ class OOCSolver:
         else:
             import jax.numpy as jnp
             ex = self._executor
-            out = np.asarray(ex.fn(jnp.asarray(tiles, dtype=ex.dtype)),
-                             dtype=np.float64)
+            dev_tiles = jnp.asarray(tiles, dtype=ex.dtype)
+            # the host copy is dead once on the device (8.6 GB at
+            # n=32768): free it before the factor comes back
+            del tiles
+            out = np.asarray(ex.fn(dev_tiles), dtype=np.float64)
         self._tiles = out
         self._factor_calls += 1
         reg = _obs_registry()
@@ -633,6 +645,15 @@ class _CompiledExecutor:
         if cfg.resolved_backend() != "jax":
             return
         import jax
+        import jax.numpy as jnp
+        from repro.kernels import pallas_interpret
+        if (cfg.fuse_columns and jnp.dtype(self.dtype) == jnp.float64
+                and not pallas_interpret()):
+            raise ValueError(
+                f"fuse_columns=True cannot run an f64 compute dtype on "
+                f"{jax.default_backend()}: the fused Pallas kernel compiles "
+                f"through Mosaic, which has no f64; use "
+                f"compute_dtype=jnp.float32 or fuse_columns=False")
         if cfg.ndev > 1:
             from .cholesky import make_multidevice_jax_executor
             self.multidevice = make_multidevice_jax_executor(

@@ -57,6 +57,7 @@ from .schedule import (HOST_IO, MultiDeviceSchedule, Op, OpKind, Schedule,
 from .precision import (PrecisionPlan, assign_precision, tile_norms,
                         uniform_plan)
 from .precision import tile_amax as _tile_amax
+from repro.kernels import pallas_interpret
 
 _NP_DTYPES = {
     "f64": np.float64,
@@ -341,11 +342,15 @@ def _make_kernel_fns(use_pallas: bool, interpret: bool):
         return wrapped
 
     if not use_pallas:
+        # HIGHEST: a TPU's default f32 matmul is one bf16 pass, which
+        # would silently factor at bf16 accuracy (XLA's Cholesky and
+        # triangular-solve expanders already use HIGHEST)
+        hi = jax.lax.Precision.HIGHEST
         fns = {
             "potrf": lambda c: jnp.linalg.cholesky(0.5 * (c + c.T)),
             "trsm": _trsm_jax,
-            "syrk": lambda c, a: c - a @ a.T,
-            "gemm": lambda c, a, b: c - a @ b.T,
+            "syrk": lambda c, a: c - jnp.matmul(a, a.T, precision=hi),
+            "gemm": lambda c, a, b: c - jnp.matmul(a, b.T, precision=hi),
         }
     else:
         from repro.kernels import ops as kops
@@ -665,16 +670,11 @@ def _donate_argnums(n: int) -> tuple:
     them to the outputs), so on accelerator backends XLA may reuse their
     HBM for the results.  CPU ignores donation with a warning per jit —
     keep it off there."""
-    try:
-        if jax.default_backend() == "cpu":
-            return ()
-    except Exception:
-        return ()
-    return tuple(range(n))
+    return () if jax.default_backend() == "cpu" else tuple(range(n))
 
 
 def make_jax_executor(sched: Schedule, compute_dtype=jnp.float64,
-                      use_pallas: bool = False, interpret: bool = True,
+                      use_pallas: bool = False, interpret: bool | None = None,
                       fuse_columns: bool = False):
     """Build a jit-able ``host_tiles -> factored host_tiles`` function.
 
@@ -691,6 +691,7 @@ def make_jax_executor(sched: Schedule, compute_dtype=jnp.float64,
     tb = sched.tb
     lad = sched.plan.ladder
     nslots = _device_nslots(sched.ops)
+    interpret = pallas_interpret(interpret)
     kf = _make_kernel_fns(use_pallas, interpret)
 
     def run(host_tiles):
@@ -712,7 +713,7 @@ def make_jax_executor(sched: Schedule, compute_dtype=jnp.float64,
 
 def run_traced_jax(sched: Schedule, host_tiles: np.ndarray, trace,
                    compute_dtype=jnp.float64, use_pallas: bool = False,
-                   interpret: bool = True) -> np.ndarray:
+                   interpret: bool | None = None) -> np.ndarray:
     """Single-device JAX execution in *measured* mode: op-by-op, eager,
     with a ``jax.block_until_ready`` fence after every op so each
     recorded span covers that op's actual execution (under async
@@ -732,7 +733,7 @@ def run_traced_jax(sched: Schedule, host_tiles: np.ndarray, trace,
                          "spill schedules trace through SpillJaxExecutor")
     tb = sched.tb
     lad = sched.plan.ladder
-    kf = _make_kernel_fns(use_pallas, interpret)
+    kf = _make_kernel_fns(use_pallas, pallas_interpret(interpret))
     host = jnp.asarray(np.asarray(host_tiles, dtype=np.float64),
                        dtype=compute_dtype)
     slots = jnp.zeros((max(_device_nslots(sched.ops), 1), tb, tb),
@@ -769,7 +770,7 @@ class SpillJaxExecutor:
     """
 
     def __init__(self, sched: Schedule, compute_dtype=jnp.float64,
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False, interpret: bool | None = None,
                  fuse_columns: bool = False):
         if sched.host_slots < 1:
             raise ValueError("SpillJaxExecutor needs a spill schedule "
@@ -778,8 +779,8 @@ class SpillJaxExecutor:
         self.compute_dtype = compute_dtype
         self.jit_traces = 0
         self.last_io_stats = None     # executed FETCH/SPILL counters
-        self._kf = _make_kernel_fns(use_pallas, interpret)
-        self._interpret = interpret
+        self._interpret = pallas_interpret(interpret)
+        self._kf = _make_kernel_fns(use_pallas, self._interpret)
         self._fuse = fuse_columns
         self._nslots = _device_nslots(sched.ops)
         self._segments = self._build_segments()
@@ -1055,7 +1056,7 @@ class MultiDeviceJaxExecutor:
     """
 
     def __init__(self, msched: MultiDeviceSchedule, compute_dtype=jnp.float64,
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False, interpret: bool | None = None,
                  devices=None, fuse_columns: bool = False):
         if msched.ndev < 2:
             raise ValueError(
@@ -1075,8 +1076,8 @@ class MultiDeviceJaxExecutor:
         self.compute_dtype = compute_dtype
         self.jit_traces = 0
         self.last_transfer_stats = None
-        self._kf = _make_kernel_fns(use_pallas, interpret)
-        self._interpret = interpret
+        self._interpret = pallas_interpret(interpret)
+        self._kf = _make_kernel_fns(use_pallas, self._interpret)
         self._fuse = fuse_columns
         # device d's host slab holds the rows of its grid row (d // q);
         # tile-level ownership within the slab follows schedule.grid_owner,
@@ -1325,7 +1326,7 @@ class MultiDeviceJaxExecutor:
 def make_multidevice_jax_executor(msched: MultiDeviceSchedule,
                                   compute_dtype=jnp.float64,
                                   use_pallas: bool = False,
-                                  interpret: bool = True,
+                                  interpret: bool | None = None,
                                   devices=None,
                                   fuse_columns: bool = False,
                                   ) -> MultiDeviceJaxExecutor:

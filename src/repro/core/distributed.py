@@ -40,12 +40,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                      # jax >= 0.6: top-level export
-    from jax import shard_map
-except ImportError:                       # older jax: experimental home
-    from jax.experimental.shard_map import shard_map
 
 from .tiling import to_tiles, from_tiles
 
@@ -129,7 +125,7 @@ def distributed_cholesky(a: np.ndarray, tb: int, mesh: Mesh, axis: str = "model"
 
         return shard_map(
             body, mesh=mesh,
-            in_specs=P(axis), out_specs=P(axis), check_rep=False,
+            in_specs=P(axis), out_specs=P(axis), check_vma=False,
         )(tiles_sharded)
 
     with mesh:

@@ -17,6 +17,9 @@ BETA_WEAK = 0.02627
 BETA_MEDIUM = 0.078809
 BETA_STRONG = 0.210158
 
+# entries per row block of matern_covariance (32 MiB of f64 per temporary)
+_BLOCK_ELEMS = 1 << 22
+
 
 def _morton_key(pts: np.ndarray, bits: int = 16) -> np.ndarray:
     """Z-order (Morton) key per point — ExaGeoStat orders locations this way
@@ -51,25 +54,37 @@ def generate_locations(n: int, seed: int = 0) -> np.ndarray:
     return pts[order]
 
 
+def _matern_kernel(h: np.ndarray, nu: float) -> np.ndarray:
+    """Unit-variance Matérn correlation at scaled distances ``h = d / a``."""
+    if nu == 0.5:
+        return np.exp(-h)
+    if nu == 1.5:
+        s = np.sqrt(3.0) * h
+        return (1.0 + s) * np.exp(-s)
+    if nu == 2.5:
+        s = np.sqrt(5.0) * h
+        return (1.0 + s + s * s / 3.0) * np.exp(-s)
+    from scipy.special import kv, gamma
+    hp = np.where(h == 0.0, 1.0, h)
+    c = (2.0 ** (1.0 - nu) / gamma(nu)) * (hp ** nu) * kv(nu, hp)
+    return np.where(h == 0.0, 1.0, c)
+
+
 def matern_covariance(locs: np.ndarray, sigma2: float = 1.0,
                       beta: float = BETA_MEDIUM, nu: float = 0.5,
                       nugget: float = 1e-6) -> np.ndarray:
-    """Dense Matérn covariance matrix Σ_θ over the given locations."""
-    d = np.sqrt(((locs[:, None, :] - locs[None, :, :]) ** 2).sum(-1))
-    h = d / beta
-    if nu == 0.5:
-        c = np.exp(-h)
-    elif nu == 1.5:
-        s = np.sqrt(3.0) * h
-        c = (1.0 + s) * np.exp(-s)
-    elif nu == 2.5:
-        s = np.sqrt(5.0) * h
-        c = (1.0 + s + s * s / 3.0) * np.exp(-s)
-    else:
-        from scipy.special import kv, gamma
-        hp = np.where(h == 0.0, 1.0, h)
-        c = (2.0 ** (1.0 - nu) / gamma(nu)) * (hp ** nu) * kv(nu, hp)
-        c = np.where(h == 0.0, 1.0, c)
-    cov = sigma2 * c
+    """Dense Matérn covariance matrix Σ_θ over the given locations.
+
+    Built in row blocks of about ``_BLOCK_ELEMS`` entries, so host memory
+    peaks at the ``n x n`` result plus one block's temporaries (a single
+    ``n x n x 2`` difference array is 17 GB at ``n = 32768``).
+    """
+    n = locs.shape[0]
+    cov = np.empty((n, n), dtype=np.float64)
+    rows = max(1, _BLOCK_ELEMS // max(n, 1))
+    for r0 in range(0, n, rows):
+        blk = locs[r0:r0 + rows]
+        d = np.sqrt(((blk[:, None, :] - locs[None, :, :]) ** 2).sum(-1))
+        cov[r0:r0 + rows] = sigma2 * _matern_kernel(d / beta, nu)
     cov[np.diag_indices_from(cov)] += nugget * sigma2
     return cov

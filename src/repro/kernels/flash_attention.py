@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -61,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
-                    bk: int = 512, interpret: bool = True):
+                    bk: int = 512, interpret: bool | None = None):
     """q: [BH, S, hd]; k/v: [BKV, T, hd] with BH = BKV * group.
 
     Returns [BH, S, hd].  S % bq == 0 and T % bk == 0 (pad upstream).
@@ -93,11 +95,11 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
             pltpu.VMEM((bq,), jnp.float32),       # running sum
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)
 
 
-def flash_gqa(q, k, v, *, causal: bool = True, interpret: bool = True,
+def flash_gqa(q, k, v, *, causal: bool = True, interpret: bool | None = None,
               bq: int = 512, bk: int = 512):
     """Convenience wrapper for model-layout tensors.
 

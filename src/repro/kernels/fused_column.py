@@ -33,10 +33,16 @@ from __future__ import annotations
 
 import functools
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_interpret
+from .potrf import chol_in_place
+from .trsm import trsm_in_place
 
 _JNP_DTYPES = {
     "f64": jnp.float64,
@@ -46,6 +52,8 @@ _JNP_DTYPES = {
     "f8e4m3": jnp.float8_e4m3fn,
     "f8e4m3s": jnp.float8_e4m3fn,
 }
+
+_VMEM_LIMIT = 64 * 1024 * 1024     # of v5e's 128 MiB
 
 # trace-time kernel dispatch counters (see launch_counts)
 _LAUNCHES = {"fused_column": 0, "tile_op": 0}
@@ -73,7 +81,7 @@ def _fp8_scale_of(amax, dtype):
     numpy agree bitwise (a log2/floor boundary could differ by one ulp
     and shift the scale a whole octave)."""
     m, e = jnp.frexp(amax)
-    exp = (8 - e) + jnp.where(m <= 0.875, 1, 0)
+    exp = (8 - e) + (m <= 0.875).astype(e.dtype)   # int32 also under x64
     s = jnp.exp2(exp.astype(dtype))
     ok = jnp.isfinite(amax) & (amax > 0)
     return jnp.where(ok, s, jnp.asarray(1.0, dtype))
@@ -81,17 +89,16 @@ def _fp8_scale_of(amax, dtype):
 
 def _round_class(x, name: str):
     """Round-trip one tile through a storage class inside the kernel
-    epilogue (the executors' ``_jx_round`` semantics: f64 degrades to the
-    compute dtype when x64 is off; the scaled-FP8 class applies its
-    store-time amax scale before the cast and inverts it after)."""
-    if name == "f64":
-        if not jax.config.jax_enable_x64 or x.dtype == jnp.float64:
-            return x
-        return x.astype(jnp.float64).astype(x.dtype)
-    if _JNP_DTYPES[name] == x.dtype:
+    epilogue (the executors' ``_jx_round`` semantics; the scaled-FP8
+    class applies its store-time amax scale before the cast and inverts
+    it after).  The f64 class is the identity: an f64 tile is already
+    exact, and an f32 tile survives the f32 -> f64 -> f32 round trip
+    unchanged (nor does Mosaic have an f64 type to cast through)."""
+    if name == "f64" or _JNP_DTYPES[name] == x.dtype:
         return x
     if name == "f8e4m3s":
-        s = _fp8_scale_of(jnp.max(jnp.abs(x)), x.dtype)
+        # amax stays a (1, 1) vector: Mosaic bitcasts (frexp) vectors only
+        s = _fp8_scale_of(jnp.max(jnp.abs(x), keepdims=True), x.dtype)
         return ((x * s).astype(jnp.float8_e4m3fn).astype(x.dtype)) / s
     return x.astype(_JNP_DTYPES[name]).astype(x.dtype)
 
@@ -106,33 +113,7 @@ def _epilogue(x, cls_id, ladder):
     return out
 
 
-def _chol_tile(c):
-    """Column-recursive in-VMEM Cholesky (the potrf.py loop)."""
-    a = 0.5 * (c + c.T)
-    n = a.shape[0]
-    rows = jax.lax.iota(jnp.int32, n)
-
-    def col(j, l):
-        v = a[:, j] - l @ l[j, :]
-        d = jnp.sqrt(v[j])
-        colv = jnp.where(rows >= j, v / d, jnp.zeros_like(v))
-        return l.at[:, j].set(colv)
-
-    return jax.lax.fori_loop(0, n, col, jnp.zeros_like(a))
-
-
-def _trsm_tile(l, c):
-    """Forward substitution X L^T = C in VMEM (the trsm.py loop)."""
-    n = l.shape[0]
-
-    def col(j, x):
-        v = (c[:, j] - x @ l[j, :]) / l[j, j]
-        return x.at[:, j].set(v)
-
-    return jax.lax.fori_loop(0, n, col, jnp.zeros_like(c))
-
-
-def _fused_kernel(c_ref, h_ref, b_ref, l_ref, cls_ref, o_ref, acc_ref,
+def _fused_kernel(cls_ref, c_ref, h_ref, b_ref, l_ref, o_ref, acc_ref,
                   l_scr, *, k_steps, with_diag, ladder):
     r = pl.program_id(0)
     kk = pl.program_id(1)
@@ -144,12 +125,12 @@ def _fused_kernel(c_ref, h_ref, b_ref, l_ref, cls_ref, o_ref, acc_ref,
     a = h_ref[0, 0].astype(acc_ref.dtype)
     b = b_ref[0].astype(acc_ref.dtype)
     acc_ref[...] -= jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())),
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=acc_ref.dtype)
 
     @pl.when(kk == k_steps - 1)
     def _final():
-        cls_id = cls_ref[0, 0]
+        cls_id = cls_ref[r]
         if with_diag:
             @pl.when(r == 0)
             def _diag():
@@ -157,21 +138,26 @@ def _fused_kernel(c_ref, h_ref, b_ref, l_ref, cls_ref, o_ref, acc_ref,
                 # row TRSMs must solve against the *stored* (class-
                 # rounded) diagonal, exactly as the unfused trace reads
                 # it back after its STORE
-                l = _epilogue(_chol_tile(acc_ref[...]), cls_id, ladder)
+                c = acc_ref[...]
+                acc_ref[...] = 0.5 * (c + c.T)
+                chol_in_place(acc_ref)
+                l = _epilogue(acc_ref[...], cls_id, ladder)
                 l_scr[...] = l
                 o_ref[0] = l.astype(o_ref.dtype)
 
             @pl.when(r > 0)
             def _row():
-                x = _trsm_tile(l_scr[...], acc_ref[...])
-                o_ref[0] = _epilogue(x, cls_id, ladder).astype(o_ref.dtype)
+                trsm_in_place(l_scr, acc_ref)
+                o_ref[0] = _epilogue(acc_ref[...], cls_id,
+                                     ladder).astype(o_ref.dtype)
         else:
-            x = _trsm_tile(l_ref[...].astype(acc_ref.dtype), acc_ref[...])
-            o_ref[0] = _epilogue(x, cls_id, ladder).astype(o_ref.dtype)
+            trsm_in_place(l_ref, acc_ref)
+            o_ref[0] = _epilogue(acc_ref[...], cls_id,
+                                 ladder).astype(o_ref.dtype)
 
 
 def fused_column_step(c_stack, hist, bhist, l_kk, cls_ids, *,
-                      ladder, with_diag: bool, interpret: bool = True):
+                      ladder, with_diag: bool, interpret: bool | None = None):
     """One fused column step: trailing update + solve, one launch.
 
     Args:
@@ -186,14 +172,23 @@ def fused_column_step(c_stack, hist, bhist, l_kk, cls_ids, *,
       l_kk: ``[tb, tb]`` pre-factored diagonal (ignored with
         ``with_diag`` — pass zeros).
       cls_ids: ``[R]`` int32 storage-class index per output row for the
-        epilogue cast (-1 leaves a row unrounded).
+        epilogue cast (-1 leaves a row unrounded); scalar-prefetched
+        into SMEM.
       ladder: the precision-plan ladder naming the class indices.
       with_diag: statically selects the POTRF-in-launch variant.
+      interpret: run the Pallas interpreter; ``None`` chooses from the
+        backend (:func:`repro.kernels.pallas_interpret`).  A compiled
+        launch refuses f64 tiles: Mosaic has no f64 type.
 
     Returns ``[R, tb, tb]``: the factored diagonal (row 0, with_diag)
     and solved panel rows, epilogue-cast per class.
     """
+    interpret = pallas_interpret(interpret)
     r_tiles, tb, _ = c_stack.shape
+    if c_stack.dtype == jnp.float64 and not interpret:
+        raise ValueError(
+            "fused_column_step cannot compile f64 tiles: Mosaic has no f64 "
+            "type; use an f32 compute dtype or fuse_columns=False")
     k_hist = hist.shape[1]
     if k_hist == 0:     # pure-solve column: accumulate an exact zero
         hist = jnp.zeros((r_tiles, 1, tb, tb), dtype=c_stack.dtype)
@@ -201,23 +196,32 @@ def fused_column_step(c_stack, hist, bhist, l_kk, cls_ids, *,
         k_hist = 1
     acc_dtype = (jnp.float64 if c_stack.dtype == jnp.float64
                  else jnp.float32)
-    cls_arr = jnp.asarray(cls_ids, dtype=jnp.int32).reshape(r_tiles, 1)
+    cls_arr = jnp.asarray(cls_ids, dtype=jnp.int32).reshape(r_tiles)
     _LAUNCHES["fused_column"] += 1
     kernel = functools.partial(_fused_kernel, k_steps=k_hist,
                                with_diag=with_diag, ladder=tuple(ladder))
-    return pl.pallas_call(
-        kernel,
+    z = np.int32(0)     # int32 block indices, also under x64 (Mosaic has no i64)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,      # cls_ids
         grid=(r_tiles, k_hist),
-        out_shape=jax.ShapeDtypeStruct((r_tiles, tb, tb), c_stack.dtype),
         in_specs=[
-            pl.BlockSpec((1, tb, tb), lambda r, kk: (r, 0, 0)),     # C
-            pl.BlockSpec((1, 1, tb, tb), lambda r, kk: (r, kk, 0, 0)),  # A
-            pl.BlockSpec((1, tb, tb), lambda r, kk: (kk, 0, 0)),    # B
-            pl.BlockSpec((tb, tb), lambda r, kk: (0, 0)),           # L in
-            pl.BlockSpec((1, 1), lambda r, kk: (r, 0)),             # cls
+            pl.BlockSpec((1, tb, tb), lambda r, kk, cls: (r, z, z)),   # C
+            pl.BlockSpec((1, 1, tb, tb),
+                         lambda r, kk, cls: (r, kk, z, z)),            # A
+            pl.BlockSpec((1, tb, tb), lambda r, kk, cls: (kk, z, z)),  # B
+            pl.BlockSpec((tb, tb), lambda r, kk, cls: (z, z)),         # L in
         ],
-        out_specs=pl.BlockSpec((1, tb, tb), lambda r, kk: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, tb, tb), lambda r, kk, cls: (r, z, z)),
         scratch_shapes=[pltpu.VMEM((tb, tb), acc_dtype),
                         pltpu.VMEM((tb, tb), acc_dtype)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r_tiles, tb, tb), c_stack.dtype),
+        # ten double-buffered tile blocks, two scratch tiles and the
+        # f32 (HIGHEST) matmul's temporaries outgrow the default 16 MiB
+        # scoped VMEM at tb=512
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(c_stack, hist, bhist, l_kk, cls_arr)
+    )(cls_arr, c_stack, hist, bhist, l_kk)

@@ -23,6 +23,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_interpret
+
+
+def _precision(dtype):
+    """f32 operands contract at full f32 (HIGHEST); narrower storage
+    operands keep the MXU's native single pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
 
 def _mxp_gemm_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, k_steps):
     @pl.when(pl.program_id(2) == 0)
@@ -32,7 +40,8 @@ def _mxp_gemm_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, k_steps):
     a = a_ref[...]
     b = b_ref[...]
     acc_ref[...] -= jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())), precision=_precision(a.dtype),
+        preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -42,7 +51,7 @@ def _mxp_gemm_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, k_steps):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def mxp_gemm_update(c: jax.Array, a: jax.Array, b: jax.Array,
                     bm: int = 128, bn: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """C - A @ B^T with f32 accumulation.  a: [M,K], b: [N,K], c: [M,N]."""
     m, k = a.shape
     n, kb = b.shape
@@ -62,5 +71,5 @@ def mxp_gemm_update(c: jax.Array, a: jax.Array, b: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(a, b, c)

@@ -17,6 +17,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_interpret
+from .mxp_gemm import _precision
+
 
 def _syrk_kernel(a_ref, a2_ref, c_ref, o_ref, acc_ref, *, k_steps):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -29,6 +32,7 @@ def _syrk_kernel(a_ref, a2_ref, c_ref, o_ref, acc_ref, *, k_steps):
     def _update():
         acc_ref[...] -= jax.lax.dot_general(
             a_ref[...], a2_ref[...], (((1,), (1,)), ((), ())),
+            precision=_precision(a_ref.dtype),
             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -38,7 +42,7 @@ def _syrk_kernel(a_ref, a2_ref, c_ref, o_ref, acc_ref, *, k_steps):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
 def syrk_update(c: jax.Array, a: jax.Array, bm: int = 128, bk: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """Lower-triangle C - A @ A^T; upper blocks of C pass through untouched
     in the block-skip region (callers that need symmetry mirror afterwards)."""
     m, k = a.shape
@@ -58,5 +62,5 @@ def syrk_update(c: jax.Array, a: jax.Array, bm: int = 128, bk: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bm), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bm), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(a, a, c)

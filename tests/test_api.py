@@ -105,6 +105,53 @@ def test_config_backend_resolution_and_hash():
     assert c2 == c3 and hash(c2) == hash(c3) and c2 != c1
 
 
+def _steer_platform(monkeypatch, platform, count):
+    """Make JAX report ``count`` devices of ``platform`` (the backend
+    choice is read from jax.devices() / jax.default_backend())."""
+    import types
+
+    import jax
+    fake = [types.SimpleNamespace(platform=platform, id=i)
+            for i in range(count)]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: fake)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+
+
+@pytest.mark.parametrize("count, kwargs, match", [
+    (1, dict(), "needs 2 devices, found 1"),
+    (4, dict(host_slots=4), "host_slots > 0 with ndev > 1"),
+])
+def test_auto_backend_never_leaves_an_accelerator(monkeypatch, count,
+                                                  kwargs, match):
+    """backend='auto' falls back to the NumPy replay only on the CPU: on
+    a TPU with too few devices (or a spill schedule only the replay
+    runs) it raises instead of quietly running on the host."""
+    cfg = repro.CholeskyConfig(tb=32, ndev=2, **kwargs)
+    _steer_platform(monkeypatch, "tpu", count)
+    with pytest.raises(RuntimeError, match=match):
+        cfg.resolved_backend()
+    _steer_platform(monkeypatch, "cpu", count)
+    assert cfg.resolved_backend() == "numpy"
+
+
+def test_auto_backend_resolves_jax_on_enough_accelerators(monkeypatch):
+    _steer_platform(monkeypatch, "tpu", 4)
+    assert repro.CholeskyConfig(tb=32, ndev=4).resolved_backend() == "jax"
+    assert repro.CholeskyConfig(tb=32).resolved_backend() == "jax"
+
+
+def test_fused_f64_refused_before_compiling_off_cpu(monkeypatch):
+    """fuse_columns with an f64 compute dtype cannot compile (Mosaic has
+    no f64): off the CPU it is refused when the executor is built, before
+    anything is traced."""
+    cfg = repro.CholeskyConfig(tb=16, policy="v3", fuse_columns=True,
+                               compute_dtype=np.float64)
+    p = repro.plan(64, cfg)
+    _steer_platform(monkeypatch, "tpu", 1)
+    with pytest.raises(ValueError, match="no f64"):
+        p.compile()
+
+
 # ---------------------------------------------------------------------------
 # plan() caching + executor reuse
 

@@ -51,7 +51,7 @@ def test_cross_pod_psum():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.optim.compress import compress_pod_gradients, ef_init
         mesh = jax.make_mesh((2,), ('pod',))
         g = jnp.stack([jnp.arange(256, dtype=jnp.float32) / 64.0,
@@ -64,7 +64,7 @@ def test_cross_pod_psum():
             return out['w'][None]
 
         f = shard_map(body, mesh=mesh, in_specs=P('pod'),
-                      out_specs=P('pod'), check_rep=False)
+                      out_specs=P('pod'), check_vma=False)
         out = jax.jit(f)(g)
         want = np.asarray(g).mean(0)
         got = np.asarray(out)[0]
