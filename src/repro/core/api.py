@@ -51,6 +51,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro.obs.timed import FACTOR, span
+
 from .precision import LADDERS, PrecisionPlan, uniform_plan
 from .schedule import (MultiDeviceSchedule, OpKind,
                        build_multidevice_schedule, build_schedule,
@@ -442,17 +444,27 @@ class OOCSolver:
         single-factor statefulness is why :class:`repro.serve`'s service
         pools one solver per session instead of sharing one solver
         across tenants.
+
+        Each call runs inside a ``jax.profiler`` span ``repro.factor``
+        (arg ``call``: the call's number on this solver, from 1); on the
+        single-device jit path its phases are child spans with their
+        bytes (:mod:`repro.obs.timed`, docs/observability.md).
         """
         a = np.asarray(a)
         if a.shape != (self.n, self.n):
             raise ValueError(
                 f"matrix shape {a.shape} does not match the plan's "
                 f"n={self.n}; build a new plan for a different size")
+        with span(FACTOR, call=self._factor_calls + 1):
+            return self._factor(a, materialize, trace)
+
+    def _factor(self, a: np.ndarray, materialize: bool, trace):
+        cfg = self._plan.config
         # tile first, widen second: an f32 matrix never gets a dense f64
         # copy next to its f64 tiles
-        tiles = to_tiles(a, self._plan.config.tb).astype(np.float64,
-                                                          copy=False)
-        cfg = self._plan.config
+        with span(FACTOR + ".tile", nbytes=8 * self.n * self.n):
+            tiles = to_tiles(a, cfg.tb).astype(np.float64, copy=False)
+        io = None       # executed FETCH/SPILL counters of a spill path
         if trace is None:
             trace = self._default_trace
         active = trace is not None and getattr(trace, "active", False)
@@ -477,7 +489,7 @@ class OOCSolver:
                 hosts = run_multidevice_spill(store, self._plan.schedule,
                                               trace=trace)
                 out = store.to_tiles()
-                self._last_io = {
+                io = {
                     "fetch_ops": sum(h.fetch_ops for h in hosts),
                     "spill_ops": sum(h.spill_ops for h in hosts),
                     "fetched_bytes": sum(h.fetched_bytes for h in hosts),
@@ -495,7 +507,7 @@ class OOCSolver:
                 h = run_schedule_spill(store, self._plan.single_schedule(),
                                        trace=trace)
                 out = store.to_tiles()
-                self._last_io = {
+                io = {
                     "fetch_ops": h.fetch_ops, "spill_ops": h.spill_ops,
                     "fetched_bytes": h.fetched_bytes,
                     "spilled_bytes": h.spilled_bytes,
@@ -509,7 +521,7 @@ class OOCSolver:
             # bounded slab buffer is the only jax-resident host state)
             out = np.asarray(self._executor.fn(tiles, trace=trace),
                              dtype=np.float64)
-            self._last_io = self._executor.spill.last_io_stats
+            io = self._executor.spill.last_io_stats
         elif active:
             # per-op spans are unobservable inside the single unrolled
             # jit: traced runs execute the same op semantics eagerly
@@ -518,24 +530,35 @@ class OOCSolver:
                                  compute_dtype=self._executor.dtype,
                                  use_pallas=cfg.use_pallas)
         else:
-            import jax.numpy as jnp
+            import jax
             ex = self._executor
-            dev_tiles = jnp.asarray(tiles, dtype=ex.dtype)
-            # the host copy is dead once on the device (8.6 GB at
-            # n=32768): free it before the factor comes back
-            del tiles
-            out = np.asarray(ex.fn(dev_tiles), dtype=np.float64)
+            with span(FACTOR + ".cast",
+                      nbytes=tiles.size * np.dtype(ex.dtype).itemsize):
+                host = np.asarray(tiles, dtype=ex.dtype)
+            with span(FACTOR + ".h2d", nbytes=host.nbytes):
+                dev_tiles = jax.device_put(host).block_until_ready()
+            with span(FACTOR + ".run"):
+                dev_out = ex.fn(dev_tiles)
+                # the host copies are dead once on the device (8.6 GB at
+                # n=32768): free them while the program runs
+                del tiles, host
+                dev_out.block_until_ready()
+            with span(FACTOR + ".d2h", nbytes=dev_out.nbytes):
+                host = np.asarray(dev_out)
+            with span(FACTOR + ".widen", nbytes=8 * host.size):
+                out = host.astype(np.float64)
+            # the device array caches its host copy: drop both
+            del dev_out, host
+        if io is not None:
+            self._last_io = io
         self._tiles = out
         self._factor_calls += 1
         reg = _obs_registry()
         if reg is not None:
-            sched = self._plan.schedule
             reg.inc("repro.factor.calls")
-            reg.inc("repro.factor.h2d_bytes", sched.loads_bytes())
-            reg.inc("repro.factor.d2h_bytes", sched.stores_bytes())
-            if sched.host_slots:
-                reg.inc("repro.factor.fetch_bytes", sched.fetch_bytes())
-                reg.inc("repro.factor.spill_bytes", sched.spill_bytes())
+            if io is not None:
+                reg.inc("repro.factor.fetch_bytes", io["fetched_bytes"])
+                reg.inc("repro.factor.spill_bytes", io["spilled_bytes"])
             reg.set_gauge("repro.factor.jit_traces",
                           self._executor.jit_traces)
         if not materialize:

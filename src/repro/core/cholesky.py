@@ -58,6 +58,7 @@ from .precision import (PrecisionPlan, assign_precision, tile_norms,
                         uniform_plan)
 from .precision import tile_amax as _tile_amax
 from repro.kernels import pallas_interpret
+from repro.obs.timed import scope
 
 _NP_DTYPES = {
     "f64": np.float64,
@@ -318,11 +319,12 @@ def _jx_round(x, cls_name, compute_dtype):
         return x
     if cls_name == "f64" and not jax.config.jax_enable_x64:
         return x  # f64 class degrades to compute dtype when x64 is off
-    if cls_name == "f8e4m3s":
-        s = _jx_fp8_scale(jnp.max(jnp.abs(x)), compute_dtype)
-        return ((x * s).astype(_JNP_DTYPES[cls_name])
-                .astype(compute_dtype)) / s
-    return x.astype(_JNP_DTYPES[cls_name]).astype(compute_dtype)
+    with jax.named_scope(scope("round", cls_name)):
+        if cls_name == "f8e4m3s":
+            s = _jx_fp8_scale(jnp.max(jnp.abs(x)), compute_dtype)
+            return ((x * s).astype(_JNP_DTYPES[cls_name])
+                    .astype(compute_dtype)) / s
+        return x.astype(_JNP_DTYPES[cls_name]).astype(compute_dtype)
 
 
 def _trsm_jax(l, c):
@@ -363,6 +365,10 @@ def _make_kernel_fns(use_pallas: bool, interpret: bool):
     return {name: counted(fn) for name, fn in fns.items()}
 
 
+_JX_OPS = (OpKind.LOAD, OpKind.STORE, OpKind.SYRK, OpKind.GEMM,
+           OpKind.POTRF, OpKind.TRSM)
+
+
 def _jx_interpret_op(host, slots, op: Op, lad, kf, compute_dtype, lrow):
     """Trace one op against a (host store, slot buffer) pair.
 
@@ -370,8 +376,16 @@ def _jx_interpret_op(host, slots, op: Op, lad, kf, compute_dtype, lrow):
     and every per-device segment of the multi-device executor; ``lrow``
     maps a global tile row to the host store's row index (identity for a
     full store, ``i // ndev`` for a device's block-cyclic row slab).
-    Returns the updated ``(host, slots)``.
+    Returns the updated ``(host, slots)``.  Each op traces inside the
+    named scope ``<kind>.<class>`` (:func:`repro.obs.timed.scope`).
     """
+    if op.kind not in _JX_OPS:
+        return host, slots      # the other kinds move nothing here
+    with jax.named_scope(scope(op.kind.value, lad[op.cls])):
+        return _jx_trace_op(host, slots, op, lad, kf, compute_dtype, lrow)
+
+
+def _jx_trace_op(host, slots, op: Op, lad, kf, compute_dtype, lrow):
     if op.kind is OpKind.LOAD:
         t = _jx_round(host[lrow(op.i), op.j], lad[op.cls], compute_dtype)
         slots = slots.at[op.slot_c].set(t)
@@ -569,9 +583,10 @@ def _flush_group_fused(group, c_init, slots, lad, cdt, kf, interpret):
     l_kk = (jnp.zeros((tb, tb), dtype=cdt) if with_diag
             else val(snaps[id(rows[0][1])]["l"]))
     cls_ids = [store_of[s].cls if s in store_of else -1 for s in c_slots]
-    out = fused_column_step(c_stack, hist, bhist, l_kk, cls_ids,
-                            ladder=lad, with_diag=with_diag,
-                            interpret=interpret)
+    with jax.named_scope(scope("column", group[0][0].k)):
+        out = fused_column_step(c_stack, hist, bhist, l_kk, cls_ids,
+                                ladder=lad, with_diag=with_diag,
+                                interpret=interpret)
     out = out.astype(cdt)
     slots = slots.at[jnp.asarray(c_slots)].set(out)
     row_of = {s: r for r, s in enumerate(c_slots)}

@@ -16,10 +16,15 @@ against.  Three layers, one import:
 - :mod:`repro.obs.metrics` — the process-wide :data:`REGISTRY`
   absorbing plan-cache stats, executor counters, and serve metrics
   under one :func:`snapshot` / :func:`render_text`.
+- :mod:`repro.obs.timed` — ``jax.profiler`` spans with their bytes on
+  the untraced path (:func:`span`, which also bumps the executed-bytes
+  counters) and the ``jax.named_scope`` names of the executor's tile ops
+  (:func:`scope`).
 """
 from .drift import MODELED_KINDS, DriftReport, drift_report, total_abs_error
 from .export import chrome_trace_measured, trace_view, write_jsonl
 from .metrics import REGISTRY, MetricsRegistry, render_text, snapshot
+from .timed import parse_scope, scope, span
 from .trace import NULL, NullRecorder, Span, TraceRecorder, is_active, resolve
 
 __all__ = [
@@ -27,4 +32,5 @@ __all__ = [
     "chrome_trace_measured", "trace_view", "write_jsonl",
     "DriftReport", "drift_report", "total_abs_error", "MODELED_KINDS",
     "MetricsRegistry", "REGISTRY", "snapshot", "render_text",
+    "span", "scope", "parse_scope",
 ]

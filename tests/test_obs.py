@@ -288,17 +288,54 @@ def test_metrics_registry_counters_and_sources():
 
 
 def test_global_registry_absorbs_solver_counters():
+    """The host<->device byte counters count what a factor copied: the
+    tile store each way on the jax backend, nothing on the NumPy one."""
     from repro import obs
-    before = obs.snapshot()["counters"].get("repro.factor.calls", 0)
+
+    def counters():
+        c = obs.snapshot()["counters"]
+        return [c.get(f"repro.factor.{k}", 0)
+                for k in ("calls", "h2d_bytes", "d2h_bytes")]
+
+    calls, h2d, d2h = counters()
     solver = api.plan(_N, CholeskyConfig(tb=_TB, policy="v3",
-                                         backend="numpy")).compile()
+                                         backend="jax")).compile()
     solver.factor(_spd())
+    store = _N * _N * np.dtype(solver._executor.dtype).itemsize
+    assert counters() == [calls + 1, h2d + store, d2h + store]
+    text = obs.render_text()
+    assert f"repro.factor.h2d_bytes {h2d + store:g}\n" in text
+
+    api.plan(_N, CholeskyConfig(tb=_TB, policy="v3",
+                                backend="numpy")).compile().factor(_spd())
+    assert counters() == [calls + 2, h2d + store, d2h + store]
     snap = obs.snapshot()
-    assert snap["counters"]["repro.factor.calls"] == before + 1
-    assert snap["counters"]["repro.factor.h2d_bytes"] > 0
     assert "plan_cache" in snap["sources"]
     assert "hits" in snap["sources"]["plan_cache"]
     assert "repro.factor.calls" in obs.render_text()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_registry_counts_executed_disk_bytes(backend):
+    """On a spill plan ``fetch_bytes``/``spill_bytes`` grow by what the
+    executor moved, and no host<->device bytes are counted."""
+    from repro import obs
+    keys = ("fetch_bytes", "spill_bytes", "h2d_bytes", "d2h_bytes")
+
+    def counters():
+        c = obs.snapshot()["counters"]
+        return [c.get(f"repro.factor.{k}", 0) for k in keys]
+
+    before = counters()
+    solver = api.plan(_N, CholeskyConfig(tb=_TB, policy="v3",
+                                         backend=backend,
+                                         host_slots=8)).compile()
+    solver.factor(_spd())
+    io = solver.stats["transfers"]
+    assert io["fetched_bytes"] > 0 and io["spilled_bytes"] > 0
+    assert counters() == [before[0] + io["fetched_bytes"],
+                          before[1] + io["spilled_bytes"],
+                          before[2], before[3]]
 
 
 def test_serve_registers_metrics_source():
