@@ -47,6 +47,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
+import types
 from typing import Any, Optional
 
 import numpy as np
@@ -358,7 +359,9 @@ class OOCSolver:
                  default_trace=None):
         self._plan = plan
         self._executor = executor
-        self._tiles = None          # this solver's factored tile store (f64)
+        # this solver's factored tile store: the compute dtype on the
+        # single-device jit path until a solve widens it, else f64
+        self._tiles = None
         self._factor_calls = 0
         self._solve_calls = 0
         self._default_trace = default_trace   # from compile(trace=...)
@@ -445,6 +448,14 @@ class OOCSolver:
         pools one solver per session instead of sharing one solver
         across tenants.
 
+        On the single-device jit path the tile store keeps the
+        executor's compute dtype (f32 with x64 off): the matrix is tiled
+        and cast in one pass, and the factor comes back from the device
+        with no widening.  :meth:`logdet` reads it as it is; the first
+        :meth:`solve` or :meth:`solve_lower` after the factor widens it
+        to f64 once.  Every other path keeps an f64 store.  The dense
+        ``L`` that ``materialize=True`` returns is f64 on every path.
+
         Each call runs inside a ``jax.profiler`` span ``repro.factor``
         (arg ``call``: the call's number on this solver, from 1); on the
         single-device jit path its phases are child spans with their
@@ -460,11 +471,7 @@ class OOCSolver:
 
     def _factor(self, a: np.ndarray, materialize: bool, trace):
         cfg = self._plan.config
-        # tile first, widen second: an f32 matrix never gets a dense f64
-        # copy next to its f64 tiles
-        with span(FACTOR + ".tile", nbytes=8 * self.n * self.n):
-            tiles = to_tiles(a, cfg.tb).astype(np.float64, copy=False)
-        io = None       # executed FETCH/SPILL counters of a spill path
+        ex = self._executor
         if trace is None:
             trace = self._default_trace
         active = trace is not None and getattr(trace, "active", False)
@@ -477,6 +484,63 @@ class OOCSolver:
                 "grid": list(self.schedule.grid),
                 "backend": cfg.resolved_backend(),
             })
+        io = None       # executed FETCH/SPILL counters of a spill path
+        if (cfg.resolved_backend() == "jax" and ex.multidevice is None
+                and ex.spill is None and not active):
+            out = self._factor_jit(a)
+        else:
+            # tile first, widen second: an f32 matrix never gets a dense
+            # f64 copy next to its f64 tiles
+            with span(FACTOR + ".tile", nbytes=8 * self.n * self.n):
+                tiles = to_tiles(a, cfg.tb).astype(np.float64, copy=False)
+            out, io = self._factor_f64(tiles, trace, active)
+        if io is not None:
+            self._last_io = io
+        self._tiles = out
+        self._factor_calls += 1
+        reg = _obs_registry()
+        if reg is not None:
+            reg.inc("repro.factor.calls")
+            if io is not None:
+                reg.inc("repro.factor.fetch_bytes", io["fetched_bytes"])
+                reg.inc("repro.factor.spill_bytes", io["spilled_bytes"])
+            reg.set_gauge("repro.factor.jit_traces",
+                          self._executor.jit_traces)
+        if not materialize:
+            return None
+        return np.tril(from_tiles(out)).astype(np.float64, copy=False)
+
+    def _factor_jit(self, a: np.ndarray) -> np.ndarray:
+        """The single-device jit path: the store is staged, factored and
+        kept in the executor's compute dtype, with no f64 copy;
+        :meth:`solve` widens it when it first needs f64 tiles."""
+        import jax
+        ex = self._executor
+        dtype = np.dtype(ex.dtype)
+        with span(FACTOR + ".tile", nbytes=a.size * dtype.itemsize):
+            host = to_tiles(a, self._plan.config.tb, dtype)
+        with span(FACTOR + ".h2d", nbytes=host.nbytes):
+            dev_tiles = jax.device_put(host).block_until_ready()
+        with span(FACTOR + ".run"):
+            dev_out = ex.fn(dev_tiles)
+            # the host copy is dead once on the device (4.3 GB at
+            # n=32768): free it while the program runs
+            del host
+            dev_out.block_until_ready()
+        with span(FACTOR + ".d2h", nbytes=dev_out.nbytes):
+            host = np.asarray(dev_out)
+        # the hand-over to the store converts nothing
+        with span(FACTOR + ".widen", nbytes=0):
+            out = _writable(host)
+        # the device array caches its host copy: drop both
+        del dev_out, host
+        return out
+
+    def _factor_f64(self, tiles: np.ndarray, trace, active: bool):
+        """Every other path, on an f64 host store: ``(store, io)``, where
+        ``io`` holds the executed FETCH/SPILL counters of a spill path."""
+        cfg = self._plan.config
+        io = None
         if self._executor.multidevice is not None:
             # per-device jitted streams + device-to-device panel broadcast
             # (or, traced, the executor's fenced op-by-op measured path)
@@ -522,54 +586,31 @@ class OOCSolver:
             out = np.asarray(self._executor.fn(tiles, trace=trace),
                              dtype=np.float64)
             io = self._executor.spill.last_io_stats
-        elif active:
-            # per-op spans are unobservable inside the single unrolled
-            # jit: traced runs execute the same op semantics eagerly
+        else:
+            # traced: per-op spans are unobservable inside the single
+            # unrolled jit, so the same op semantics run eagerly
             from .cholesky import run_traced_jax
             out = run_traced_jax(self._plan.single_schedule(), tiles, trace,
                                  compute_dtype=self._executor.dtype,
                                  use_pallas=cfg.use_pallas)
-        else:
-            import jax
-            ex = self._executor
-            with span(FACTOR + ".cast",
-                      nbytes=tiles.size * np.dtype(ex.dtype).itemsize):
-                host = np.asarray(tiles, dtype=ex.dtype)
-            with span(FACTOR + ".h2d", nbytes=host.nbytes):
-                dev_tiles = jax.device_put(host).block_until_ready()
-            with span(FACTOR + ".run"):
-                dev_out = ex.fn(dev_tiles)
-                # the host copies are dead once on the device (8.6 GB at
-                # n=32768): free them while the program runs
-                del tiles, host
-                dev_out.block_until_ready()
-            with span(FACTOR + ".d2h", nbytes=dev_out.nbytes):
-                host = np.asarray(dev_out)
-            with span(FACTOR + ".widen", nbytes=8 * host.size):
-                out = host.astype(np.float64)
-            # the device array caches its host copy: drop both
-            del dev_out, host
-        if io is not None:
-            self._last_io = io
-        self._tiles = out
-        self._factor_calls += 1
-        reg = _obs_registry()
-        if reg is not None:
-            reg.inc("repro.factor.calls")
-            if io is not None:
-                reg.inc("repro.factor.fetch_bytes", io["fetched_bytes"])
-                reg.inc("repro.factor.spill_bytes", io["spilled_bytes"])
-            reg.set_gauge("repro.factor.jit_traces",
-                          self._executor.jit_traces)
-        if not materialize:
-            return None
-        return np.tril(from_tiles(out))
+        return out, io
 
     def _factored_tiles(self) -> np.ndarray:
         if self._tiles is None:
             raise RuntimeError("no factor available: call factor(a) before "
                                "solve()/solve_lower()/logdet()")
         return self._tiles
+
+    def _f64_tiles(self) -> np.ndarray:
+        """The store in f64, as the substitution reads it.  A store kept
+        in the compute dtype is widened once, at the first solve after
+        its factor, and the f64 store replaces it."""
+        tiles = self._factored_tiles()
+        if tiles.dtype != np.float64:
+            with span("repro.solve.widen", nbytes=8 * tiles.size):
+                tiles = tiles.astype(np.float64)
+            self._tiles = tiles
+        return tiles
 
     def _check_rhs(self, b) -> np.ndarray:
         """Eager rhs validation: reject shape/dtype mismatches with a
@@ -599,9 +640,14 @@ class OOCSolver:
         ``(n, k)`` — the blocked substitution sweeps the tile store once
         for the whole stack, which is what the serve batcher exploits
         to coalesce concurrent single-RHS solves.  The result is
-        against this solver's *current* factor (see :meth:`factor`)."""
+        against this solver's *current* factor (see :meth:`factor`).
+
+        The substitution runs in f64: a store kept in the compute dtype
+        is widened on the first solve after its factor (span
+        ``repro.solve.widen``, counter ``repro.solve.widen_bytes``), and
+        later solves against the same factor reuse the f64 store."""
         from .solve import cho_solve_tiles
-        x = cho_solve_tiles(self._factored_tiles(), self._check_rhs(b))
+        x = cho_solve_tiles(self._f64_tiles(), self._check_rhs(b))
         self._solve_calls += 1
         reg = _obs_registry()
         if reg is not None:
@@ -611,9 +657,10 @@ class OOCSolver:
     def solve_lower(self, b: np.ndarray) -> np.ndarray:
         """Forward substitution ``L z = b`` (e.g. Gaussian quad forms);
         like :meth:`solve`, accepts one vector or ``(n, k)`` stacked
-        columns against the current factor."""
+        columns against the current factor, and widens the store to f64
+        as :meth:`solve` does."""
         from .solve import solve_lower_tiles
-        z = solve_lower_tiles(self._factored_tiles(), self._check_rhs(b))
+        z = solve_lower_tiles(self._f64_tiles(), self._check_rhs(b))
         self._solve_calls += 1
         reg = _obs_registry()
         if reg is not None:
@@ -621,7 +668,9 @@ class OOCSolver:
         return z
 
     def logdet(self) -> float:
-        """``log|A|`` of the last factored matrix, from the tile store."""
+        """``log|A|`` of the last factored matrix, from the diagonal
+        tiles of the store in its own dtype, each diagonal widened to
+        f64 for the ``log``: no widening of the store."""
         from .solve import logdet_tiles
         return logdet_tiles(self._factored_tiles())
 
@@ -632,6 +681,19 @@ class OOCSolver:
         with :func:`repro.core.analytics.crosscheck_executed_volume`."""
         mdx = self._executor.multidevice
         return None if mdx is None else mdx.last_transfer_stats
+
+
+def _writable(host: np.ndarray) -> np.ndarray:
+    """A writable array over the memory of ``host``, the read-only host
+    copy of a device array that its caller drops at once.  The factor
+    store stays its holder's to change, as a store made on the host is,
+    without copying it into fresh pages (2.4 GB at n=24576 in f32)."""
+    if host.flags.writeable:
+        return host
+    iface = dict(host.__array_interface__, data=(host.ctypes.data, False))
+    own = np.asarray(types.SimpleNamespace(__array_interface__=iface,
+                                           base=host))
+    return own.view(host.dtype)     # typestr drops extension dtypes
 
 
 def _resolved_dtype(cfg: CholeskyConfig):

@@ -115,12 +115,14 @@ def logdet_tiles(tiles: np.ndarray) -> float:
     A valid Cholesky factor has strictly positive diagonal entries; a
     non-positive entry means the factorization failed upstream (loss of
     positive definiteness, e.g. under an MxP ladder too aggressive for
-    the matrix) and ``log`` would silently produce NaN/-inf.
+    the matrix) and ``log`` would silently produce NaN/-inf.  Each
+    diagonal is widened to f64 before the ``log``, so a store kept in a
+    narrower compute dtype gives the sum its f64 store would.
     """
     nt = tiles.shape[0]
     acc = 0.0
     for i in range(nt):
-        d = np.diag(tiles[i, i])
+        d = np.diag(tiles[i, i]).astype(np.float64, copy=False)
         if not np.all(d > 0.0):
             bad = np.flatnonzero(~(d > 0.0))
             raise ValueError(

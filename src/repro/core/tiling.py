@@ -86,13 +86,15 @@ class TileLayout:
         return grid_owner(i, j, p, q)
 
 
-def to_tiles(a: np.ndarray, tb: int) -> np.ndarray:
-    """[n, n] -> [Nt, Nt, tb, tb] host tile store."""
+def to_tiles(a: np.ndarray, tb: int, dtype=None) -> np.ndarray:
+    """[n, n] -> [Nt, Nt, tb, tb] host tile store, a fresh C-ordered
+    array in ``dtype`` (default: ``a``'s).  The cast runs inside the one
+    strided copy, so a narrower store is never first made in ``a``'s
+    dtype."""
     n = a.shape[0]
     nt = n // tb
-    return (
-        a.reshape(nt, tb, nt, tb).transpose(0, 2, 1, 3).copy()
-    )
+    return np.array(a.reshape(nt, tb, nt, tb).transpose(0, 2, 1, 3),
+                    dtype=dtype, order="C")
 
 
 def from_tiles(t: np.ndarray) -> np.ndarray:
