@@ -19,17 +19,25 @@ def _applies(metric: dict, name: str) -> bool:
 
 def load(name: str, spec_path: Path = ROOT / "BENCHMARK.json") -> dict:
     """The cell ``name`` with its configuration, traffic and limits read
-    from their files, and the metrics ``BENCHMARK.json`` gives it."""
+    from their files, and the metrics ``BENCHMARK.json`` gives it.  A
+    workload whose ``chips`` differ from its configuration's ``ndev`` is
+    refused: the layout lives in the configuration alone."""
     spec = json.loads(Path(spec_path).read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     cfg = {c["name"]: c for c in spec["configs"]}[w["config"]]
+    config = json.loads((ROOT / cfg["file"]).read_text())
+    ndev = layout(config)["ndev"]
+    if w["chips"] != ndev:
+        raise ValueError(f"workload {name!r} asks for {w['chips']} chips, "
+                         f"its configuration {w['config']!r} lays the "
+                         f"factor out over ndev={ndev}")
     return {
         "name": name,
         "chips": w["chips"],
-        "config": json.loads((ROOT / cfg["file"]).read_text()),
+        "config": config,
         "traffic": json.loads(
             (BENCH / "traffic" / f"{w['traffic']}.json").read_text()),
         "limits": json.loads(
@@ -37,6 +45,17 @@ def load(name: str, spec_path: Path = ROOT / "BENCHMARK.json") -> dict:
         "end_to_end": [m for m in spec["end_to_end"] if _applies(m, name)],
         "per_layer": [m for m in spec["per_layer"] if _applies(m, name)],
     }
+
+
+def layout(config: dict) -> dict:
+    """The device layout the configuration states: ``ndev`` and, where
+    given, ``grid`` (p, q); one device where the file has no ``layout``."""
+    lay = config.get("layout", {"ndev": 1})
+    unknown = set(lay) - {"ndev", "grid"}
+    if unknown:
+        raise ValueError(f"layout keys {sorted(unknown)}: it states ndev "
+                         f"and grid")
+    return lay
 
 
 def build_matrix(config: dict, seed: int) -> np.ndarray:
@@ -70,8 +89,8 @@ def classes_as_run(config: dict) -> list:
             for row in class_map(config)]
 
 
-def make_solver(config: dict):
-    """``repro.plan(n, cfg).compile()`` for the configuration."""
+def solver_config(config: dict):
+    """The ``repro.CholeskyConfig`` the configuration states."""
     import repro
     from repro.core.precision import EPS, LADDERS, PrecisionPlan
     p = config["precision"]
@@ -79,7 +98,14 @@ def make_solver(config: dict):
     classes = np.array([[ladder.index(c) for c in row]
                         for row in class_map(config)], dtype=np.int8)
     eps = p["eps_target"] if p["kind"] == "map" else EPS[p["class"]]
-    cfg = repro.CholeskyConfig(tb=config["tb"], policy=config["policy"],
-                               backend="jax",
-                               plan=PrecisionPlan(classes, ladder, eps))
-    return repro.plan(config["n"], cfg).compile()
+    lay = layout(config)
+    return repro.CholeskyConfig(
+        tb=config["tb"], policy=config["policy"], backend="jax",
+        plan=PrecisionPlan(classes, ladder, eps), ndev=lay["ndev"],
+        grid=tuple(lay["grid"]) if "grid" in lay else None)
+
+
+def make_solver(config: dict):
+    """``repro.plan(n, cfg).compile()`` for the configuration."""
+    import repro
+    return repro.plan(config["n"], solver_config(config)).compile()

@@ -1,7 +1,8 @@
 """Host seconds per factor: the length of each ``bench.factor`` span less
-the device-busy time inside it, averaged over the factors traced.  It is
-the host staging of ``OOCSolver.factor``: tiling, widening, casting and
-the host<->device copies."""
+the time inside it in which at least one chip used was busy, averaged
+over the factors traced.  It is the host staging of
+``OOCSolver.factor``: tiling, widening, casting and the host<->device
+copies."""
 from bench import tracing
 
 
@@ -10,5 +11,6 @@ def read(ctx):
     spans = tr.span_list("bench.factor")
     if not spans:
         return None
-    host = [(e - s) - tracing.busy_ns(tr, s, e) for s, e in spans]
+    host = [(e - s) - tracing.any_busy_ns(tr, s, e, ctx["chips"])
+            for s, e in spans]
     return sum(host) / len(host) / 1e9

@@ -113,6 +113,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     t0 = time.perf_counter()
     solver = cells.make_solver(config)
     plan_s = time.perf_counter() - t0
+    ran = {"ndev": solver.config.ndev, "grid": list(solver.schedule.grid)}
     t0 = time.perf_counter()
     driver = loop.Driver(solver, a, config, traffic, seed)
     del solver
@@ -165,7 +166,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": int(peak)}
+              **ran, "memory_peak_bytes": int(peak)}
     # the check judges the last factor and every solve; a step that gives
     # no finite log-determinant or solve has failed on its own
     result = {"correct": correct, "attempted": w["steps"],
@@ -174,9 +175,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         tr = tracing.load(tracing.latest_xplane(str(trace_dir)),
                           host_ops=not require_tpu)
         lo, hi = tr.window()
-        device["busy_s"] = tracing.busy_ns(tr, lo, hi) / 1e9
+        chips = cell["chips"]
+        device["busy_s"] = tracing.busy_ns(tr, lo, hi, chips) / 1e9
         device["window_s"] = (hi - lo) / 1e9
-        ctx = {"trace": tr, "config": config,
+        ctx = {"trace": tr, "config": config, "chips": chips,
                "classes": cells.classes_as_run(config),
                "device_kind": devices[0].device_kind}
         values = {m["name"]: read_metric(m["name"], ctx)
@@ -196,7 +198,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                          for m in shown if values[m["name"]] is not None}
     result["device"] = device
     if trace:
-        result["breakdown"] = {"device_ops": tracing.top_ops(tr, lo, hi),
+        result["breakdown"] = {"device_ops": tracing.top_ops(tr, lo, hi,
+                                                             chips),
                                "idle_gaps": tracing.idle_gaps(tr, lo, hi)}
     result["checks"] = {"backward_error": {"value": err, "limit": limit}}
     return result

@@ -369,28 +369,29 @@ def link_gbs(ctx: dict, phase: str) -> float | None:
 
 def scope_busy_s(ctx: dict, kind: str) -> float | None:
     """Device-busy seconds per factor of the ops whose innermost scope
-    is ``<kind>.*``, averaged over the devices; None where no op of the
-    trace carries a scope."""
+    is ``<kind>.*``, averaged over the chips used; None where no op of
+    the trace carries a scope."""
     from bench import tracing
     got = factors(ctx)
     if got is None or not got[0].scoped:
         return None
     sp, calls = got
     busy = 0.0
-    for ops in sp.ops.values():
+    for ops in tracing.chip_ops(sp.ops, ctx["chips"]):
         mine = tracing.union((s, e) for s, e, _, scope in ops
                              if scope and scope.startswith(kind + "."))
         busy += sum(tracing.covered(mine, s, e) for s, e, _ in calls)
-    return busy / len(sp.ops) / len(calls) / 1e9
+    return busy / ctx["chips"] / len(calls) / 1e9
 
 
-def scope_breakdown(sp: Spans, lo: float, hi: float) -> dict:
+def scope_breakdown(sp: Spans, lo: float, hi: float, chips: int) -> dict:
     """Device-busy seconds in ``[lo, hi]`` by innermost scope, an op with
-    none under ``unscoped:<hlo name>``, averaged over the devices.  An
+    none under ``unscoped:<hlo name>``, averaged over the chips used.  An
     op overlapping another is cut to the stretch no earlier op covered,
     so the parts add up to the device-busy time."""
+    from bench import tracing
     out: dict = defaultdict(float)
-    for ops in sp.ops.values():
+    for ops in tracing.chip_ops(sp.ops, chips):
         t = lo
         for s, e, name, scope in ops:
             s, e = max(s, t), min(e, hi)
@@ -398,17 +399,19 @@ def scope_breakdown(sp: Spans, lo: float, hi: float) -> dict:
                 continue
             out[scope or f"unscoped:{hlo_name(name)}"] += (e - s) / 1e9
             t = e
-    return {k: v / max(1, len(sp.ops)) for k, v in
+    return {k: v / chips for k, v in
             sorted(out.items(), key=lambda kv: -kv[1])}
 
 
 # -- a report of one traced run, for PERF.md -------------------------------
 
-def report(path: str, host_ops: bool = False) -> dict:
-    """Each ``bench.factor`` span of the trace at ``path``: its seconds,
-    the ``repro.factor`` phases in it with their seconds and bytes, the
-    share of the span they cover, its device-busy seconds and the sum of
-    their split by scope; and that split averaged over the factors."""
+def report(path: str, chips: int, host_ops: bool = False) -> dict:
+    """Each ``bench.factor`` span of the trace at ``path``, of a run on
+    ``chips`` chips: its seconds, the ``repro.factor`` phases in it with
+    their seconds and bytes, the share of the span they cover, its
+    device-busy seconds and the sum of their split by scope (both
+    averaged over the chips); and that split averaged over the
+    factors."""
     from bench import tracing
     sp, tr = load(path), tracing.load(path, host_ops=host_ops)
     factors, by_scope = [], defaultdict(float)
@@ -416,13 +419,13 @@ def report(path: str, host_ops: bool = False) -> dict:
         phases = [(n[len(FACTOR) + 1:], (e - s) / 1e9, a.get("bytes"))
                   for s, e, n, a in sp.spans
                   if n.startswith(FACTOR + ".") and lo <= s and e <= hi]
-        parts = scope_breakdown(sp, lo, hi)
+        parts = scope_breakdown(sp, lo, hi, chips)
         for k, v in parts.items():
             by_scope[k] += v
         factors.append({
             "s": (hi - lo) / 1e9, "phases": phases,
             "covered": sum(p[1] for p in phases) / ((hi - lo) / 1e9),
-            "busy_s": tracing.busy_ns(tr, lo, hi) / 1e9,
+            "busy_s": tracing.busy_ns(tr, lo, hi, chips) / 1e9,
             "by_scope_sum_s": sum(parts.values())})
     n = max(1, len(factors))
     return {"factors": factors,
@@ -431,9 +434,11 @@ def report(path: str, host_ops: bool = False) -> dict:
 
 
 if __name__ == "__main__":
-    # PYTHONPATH=src python -m bench.spans [XPLANE]: the newest trace
-    # under .bench_cache/trace by default
+    # PYTHONPATH=src python -m bench.spans [XPLANE [CHIPS]]: the newest
+    # trace under .bench_cache/trace, of a run on one chip, by default
     import json
     import sys
     print(json.dumps(report(sys.argv[1] if len(sys.argv) > 1
-                            else latest_xplane()), indent=1))
+                            else latest_xplane(),
+                            int(sys.argv[2]) if len(sys.argv) > 2 else 1),
+                     indent=1))

@@ -24,7 +24,8 @@ def _metric(name, ctx):
 
 
 def _factor(t0, call, scale=1):
-    """One ``repro.factor`` span at ``t0`` with its six phases (ns)."""
+    """One ``repro.factor`` span at ``t0`` with six phases (ns), the
+    ``cast`` that the readers still sum among them."""
     out, t = [(t0, t0 + 100 * scale, R, {"call": call})], t0
     for ph, ns, nbytes in (("tile", 10, 800), ("cast", 20, 400),
                            ("h2d", 4, 400), ("run", 30, None),
@@ -37,7 +38,7 @@ def _factor(t0, call, scale=1):
 
 def _hand(monkeypatch, sp):
     monkeypatch.setattr(spans, "current", lambda: sp)
-    return {"trace": Trace({}, [(0, 1000, W)])}
+    return {"trace": Trace({}, [(0, 1000, W)]), "chips": 1}
 
 
 def test_metrics_on_hand_made_spans(monkeypatch):
@@ -58,7 +59,7 @@ def test_metrics_on_hand_made_spans(monkeypatch):
     # round ops inside the factors: 10 ns and 20 ns over two factors
     assert got["round_s.factor"] == pytest.approx(15e-9)
     # the breakdown cuts overlaps, so the parts add up to the busy time
-    parts = spans.scope_breakdown(sp, 0, 1000)
+    parts = spans.scope_breakdown(sp, 0, 1000, 1)
     assert parts == pytest.approx({"round.bf16": 30e-9, "store.bf16": 10e-9,
                                    "unscoped:copy.3": 10e-9})
 
@@ -103,12 +104,13 @@ def test_a_factor_recorded_on_the_cpu(tmp_path):
     for s, e, _ in calls:
         got = [(n_, a_.get("bytes")) for s_, e_, n_, a_ in sp.spans
                if n_.startswith(R + ".") and s <= s_ and e_ <= e]
-        assert got == [(f"{R}.tile", 8 * n * n), (f"{R}.cast", store),
-                       (f"{R}.h2d", store), (f"{R}.run", None),
-                       (f"{R}.d2h", store), (f"{R}.widen", 8 * n * n)]
+        # the store is tiled straight to the compute dtype and kept in it
+        assert got == [(f"{R}.tile", store), (f"{R}.h2d", store),
+                       (f"{R}.run", None), (f"{R}.d2h", store),
+                       (f"{R}.widen", 0)]
     # a CPU trace keeps the scopes in the HLO of /host:metadata
     assert sp.scoped
-    parts = spans.scope_breakdown(sp, calls[0][0], calls[-1][1])
+    parts = spans.scope_breakdown(sp, calls[0][0], calls[-1][1], 1)
     scoped = [k.split(".") for k in parts if not k.startswith("unscoped:")]
     # every tile op kind, all of the one class of a uniform plan
     assert {k for k, _ in scoped} == {"potrf", "trsm", "syrk", "gemm",
@@ -121,15 +123,15 @@ def test_a_factor_recorded_on_the_cpu(tmp_path):
 
     # the report: per bench.factor, the phases cover the span, and the
     # split by scope adds up to its busy time
-    rep = spans.report(path, host_ops=True)
+    rep = spans.report(path, 1, host_ops=True)
     assert len(rep["factors"]) == 2
     for f in rep["factors"]:
-        assert [p[0] for p in f["phases"]] == ["tile", "cast", "h2d", "run",
-                                              "d2h", "widen"]
+        assert [p[0] for p in f["phases"]] == ["tile", "h2d", "run", "d2h",
+                                              "widen"]
         assert 0.5 < f["covered"] <= 1
         assert f["by_scope_sum_s"] == pytest.approx(f["busy_s"])
 
-    ctx = {"trace": tracing.load(path, host_ops=True)}
+    ctx = {"trace": tracing.load(path, host_ops=True), "chips": 1}
     spans._LOADED.clear()
     spans.TRACES, old = tmp_path, spans.TRACES
     try:
@@ -200,7 +202,7 @@ def test_device_scopes_from_event_metadata(tmp_path):
     assert got == [(1000, 1002, "convert.1", "round.bf16"),
                    (1005, 1007, "fusion.2", "gemm.f32"),
                    (1009, 1011, "copy.3", None)]
-    assert spans.scope_breakdown(sp, 0, 2000) == pytest.approx(
+    assert spans.scope_breakdown(sp, 0, 2000, 1) == pytest.approx(
         {"round.bf16": 2e-9, "gemm.f32": 2e-9, "unscoped:copy.3": 2e-9})
 
 
@@ -257,3 +259,19 @@ def test_a_traced_mxp_run_reports_its_metrics(monkeypatch, cell_of):
     assert set(METRICS) <= set(got)
     assert got["round_s.factor"]["value"] >= 0
     assert got["h2d_gbs.factor"]["value"] > 0
+
+
+def test_round_time_is_averaged_over_the_chips_used(monkeypatch):
+    """Rounding on two of four chips: its time is over the four; a plane
+    of a device the run did not use is left out."""
+    ops = {"/device:TPU:0": [(120, 130, "%convert.1", "round.bf16")],
+           "/device:TPU:2": [(320, 350, "%convert.2", "round.bf16")],
+           "/device:TPU:5": [(120, 190, "%convert.3", "round.bf16")]}
+    ctx = _hand(monkeypatch, Spans(_factor(100, 1) + _factor(300, 2), ops,
+                                   True))
+    ctx["chips"] = 4
+    assert _metric("round_s.factor", ctx) == pytest.approx(
+        (10 + 30) / 4 / 2 * 1e-9)
+    sp = spans.current()
+    assert spans.scope_breakdown(sp, 0, 1000, 4) == pytest.approx(
+        {"round.bf16": 40e-9 / 4})

@@ -6,22 +6,30 @@ Two kinds of interval come out of a trace:
 * device ops: the events of the ``XLA Ops`` line of each ``/device:``
   plane.  A trace recorded on the CPU has no such plane; only where the
   caller asks for it (``host_ops=True``, in the tests) do the events that
-  carry an ``hlo_op`` stat on the host threads stand in for them.  A
-  trace with no device ops otherwise raises;
+  carry an ``hlo_op`` stat on the host threads stand in for them, each
+  under ``/cpu:<device ordinal>``.  A trace with no device ops otherwise
+  raises;
 * spans: the benchmark's own ``jax.profiler.TraceAnnotation`` events,
   named ``bench.<what>``, on the host plane.
 
 JAX's profiler puts the host and device events on one clock, so a span's
 interval can be intersected with the device ops directly.
+
+A run uses the chips of ordinals ``0 .. chips - 1``.  The busy times and
+the op totals are taken over those chips alone: a used chip whose plane
+has no op line counts as idle, and a plane of any other device is left
+out.
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
 import os
+import re
 from collections import defaultdict
 
 SPAN_PREFIX = "bench."
+_ORDINAL = re.compile(r":(\d+)$")
 
 
 @dataclasses.dataclass
@@ -101,25 +109,51 @@ def covered(merged, lo, hi) -> float:
     return sum(e - s for s, e in clip(merged, lo, hi))
 
 
-def busy_ns(trace: Trace, lo: float, hi: float) -> float:
-    """Device-busy nanoseconds in ``[lo, hi]``, averaged over devices."""
-    if not trace.ops:
-        return 0.0
+def chip_ops(ops: dict, chips: int) -> list:
+    """The op lists of ``ops`` (device -> sorted events) of the chips a run
+    used, by ordinal ``0 .. chips - 1`` (``/device:TPU:<ordinal>``,
+    ``/cpu:<ordinal>``): an empty list for a chip with no op line; the
+    planes of other devices are left out."""
+    by = defaultdict(list)
+    for dev, v in ops.items():
+        m = _ORDINAL.search(dev)
+        if m:
+            by[int(m.group(1))] += v
+    return [sorted(by[i], key=lambda ev: ev[:2]) for i in range(chips)]
+
+
+def chip_busy_ns(trace: Trace, lo: float, hi: float, chips: int) -> float:
+    """Device-busy nanoseconds in ``[lo, hi]`` summed over the chips used:
+    the chip-time spent."""
     return sum(covered(union(v), lo, hi)
-               for v in trace.ops.values()) / len(trace.ops)
+               for v in chip_ops(trace.ops, chips))
 
 
-def top_ops(trace: Trace, lo: float, hi: float, k: int = 10) -> list:
+def busy_ns(trace: Trace, lo: float, hi: float, chips: int) -> float:
+    """Device-busy nanoseconds in ``[lo, hi]``, averaged over the chips
+    used."""
+    return chip_busy_ns(trace, lo, hi, chips) / chips
+
+
+def any_busy_ns(trace: Trace, lo: float, hi: float, chips: int) -> float:
+    """Nanoseconds of ``[lo, hi]`` in which at least one chip used was
+    busy."""
+    ops = chip_ops(trace.ops, chips)
+    return covered(union(iv for v in ops for iv in v), lo, hi)
+
+
+def top_ops(trace: Trace, lo: float, hi: float, chips: int,
+            k: int = 10) -> list:
     """``[[op name, seconds]]`` of the ``k`` ops that took most device time
-    in ``[lo, hi]``, summed over their runs and averaged over devices."""
+    in ``[lo, hi]``, summed over their runs and averaged over the chips
+    used."""
     tot: dict = defaultdict(float)
-    for v in trace.ops.values():
+    for v in chip_ops(trace.ops, chips):
         for s, e, name in v:
             if e > lo and s < hi:
                 tot[name] += min(e, hi) - max(s, lo)
-    nd = max(1, len(trace.ops))
     best = sorted(tot.items(), key=lambda kv: -kv[1])[:k]
-    return [[name, ns / nd / 1e9] for name, ns in best]
+    return [[name, ns / chips / 1e9] for name, ns in best]
 
 
 def idle_gaps(trace: Trace, lo: float, hi: float, k: int = 10) -> list:
